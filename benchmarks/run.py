@@ -17,6 +17,8 @@ import sys
 import time
 from pathlib import Path
 
+from repro.launch import compile_cache
+
 from . import fig5_throughput, fig6_utilization, roofline, serve_bench
 from .common import validate_bench_json
 
@@ -67,6 +69,7 @@ def main(argv=None) -> int:
                     help="path for the machine-readable serve results "
                          "(default: repo root); empty string disables")
     args = ap.parse_args(argv)
+    print(f"[compile cache: {compile_cache.enable(REPO_ROOT)}]")
     only = set(args.only.split(",")) if args.only else None
 
     def want(name: str) -> bool:

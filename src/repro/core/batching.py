@@ -363,11 +363,11 @@ class Stepper:
             state = st.step(state, 64)  # <= 64 VM dispatches
         out = st.result(state)          # == fn(*args), bit-exactly
 
-    Snapshots are donatable: on accelerator backends ``step``, ``inject``
-    and ``park`` donate the incoming snapshot — do not reuse a snapshot
-    after passing it in.  Chaining segments of any sizes is bit-exact with
-    the single-shot call for every schedule x fuse x mesh combination
-    (property-tested in ``tests/test_core_property.py``).
+    Snapshots are donated: ``step``, ``inject`` and ``park`` consume the
+    incoming snapshot — do not reuse a snapshot after passing it in.
+    Chaining segments of any sizes is bit-exact with the single-shot call
+    for every schedule x fuse x mesh combination (property-tested in
+    ``tests/test_core_property.py``).
     """
 
     def __init__(self, fn: "AutobatchedFunction", inputs: dict, z: int):

@@ -58,8 +58,11 @@ The only cross-device traffic per iteration is scalar all-reduces:
 
 All schedules stay bit-exact under sharding: block bodies are per-lane, and
 the reductions above are integer min/sum/argmax, which are associative and
-placement-independent.  The loop-carried state is donated on accelerator
-backends so steady-state memory is flat at one copy of the VM state.
+placement-independent.  (The lanes' own float arithmetic is exact too when
+XLA compiles it the same at the per-device batch size; on TPU a contraction
+it tiles by batch size is not, see ``docs/architecture.md``.)  The
+loop-carried state is donated, so steady-state memory is flat at one copy
+of the VM state.
 
 Segmented (resumable) execution:
 
@@ -76,9 +79,9 @@ its ``cond``.  Between segments the host may retire finished lanes
 (``lane_done``), park idle ones (``park``), and re-initialize a masked
 subset with fresh inputs (``inject``) — the primitive underneath
 retire-and-refill continuous batching (see ``repro/serve/engine.py``).
-Snapshots are donatable pytrees: on accelerator backends every
-state-in/state-out entry point donates its input snapshot, so steady-state
-memory stays flat at one copy of the VM state.
+Snapshots are donatable pytrees: every state-in/state-out entry point
+donates its input snapshot, so steady-state memory stays flat at one copy
+of the VM state.
 
 Fault containment (``VMConfig.on_fault``):
 
@@ -549,20 +552,16 @@ class ProgramCounterVM:
         # One-program path (kept for .lower()/cost_analysis), plus a
         # two-stage path for run(): init and loop are jitted separately so
         # the loop-carried state pytree can be donated — steady-state
-        # memory stays flat at one copy of the VM state.  XLA's CPU client
-        # does not implement donation, so only donate on accelerators
-        # (avoids a warning per compile).
+        # memory stays flat at one copy of the VM state.
         self._jitted = jax.jit(self._run)
-        self._donate = jax.default_backend() != "cpu"
         self._jitted_start = jax.jit(self._start)
         self._jitted_loop = jax.jit(self._loop, donate_argnums=(0,))
         # Segmented-execution entry points.  All take the state snapshot
-        # first and donate it (where the backend supports donation), so a
-        # resumable run is as memory-flat as a single-shot one.
-        donate = (0,) if self._donate else ()
-        self._jitted_segment = jax.jit(self._segment, donate_argnums=donate)
-        self._jitted_inject = jax.jit(self._inject, donate_argnums=donate)
-        self._jitted_park = jax.jit(self._park, donate_argnums=donate)
+        # first and donate it, so a resumable run is as memory-flat as a
+        # single-shot one.
+        self._jitted_segment = jax.jit(self._segment, donate_argnums=(0,))
+        self._jitted_inject = jax.jit(self._inject, donate_argnums=(0,))
+        self._jitted_park = jax.jit(self._park, donate_argnums=(0,))
 
     # ------------------------------------------------------------------
     # State construction
@@ -1190,20 +1189,16 @@ class ProgramCounterVM:
         return lax.while_loop(cond, self._make_body(), state)
 
     def run(self, inputs: dict[str, Array]) -> VMResult:
-        """Execute the batched program to completion (jitted end-to-end).
+        """Execute the batched program to completion.
 
-        On accelerators this runs two jitted stages — state construction,
-        then the while loop with the state pytree donated into it — so a
-        run never holds more than one copy of the VM state.  On CPU (no
-        donation support) the single composed program is used; the staged
-        path would just cost an extra compile and dispatch.
+        Runs two jitted stages — state construction, then the while loop
+        with the state pytree donated into it — so a run never holds more
+        than one copy of the VM state.
         """
         # Host-side profiler annotation: a jax.profiler trace of the
         # caller shows VM runs as named spans that device profiles (and
         # DispatchTrace timelines) can be lined up against.
         with jax.profiler.TraceAnnotation("pcvm.run"):
-            if not self._donate:
-                return self._result(self._jitted(inputs))
             state = self._jitted_start(inputs)
             state = self._jitted_loop(state)
             return self._result(state)
@@ -1225,11 +1220,10 @@ class ProgramCounterVM:
     ) -> dict[str, Any]:
         """Advance a snapshot by at most ``num_steps`` loop iterations.
 
-        Returns the updated snapshot (the input snapshot is donated on
-        accelerator backends — do not reuse it).  A chain of segments of
-        any sizes is bit-exact with a single :meth:`run`: the segment loop
-        runs the identical body and the snapshot carries every piece of
-        execution state.  ``num_steps`` counts loop iterations — single
+        Returns the updated snapshot (the input snapshot is donated — do
+        not reuse it).  A chain of segments of any sizes is bit-exact with
+        a single :meth:`run`: the segment loop runs the identical body and
+        the snapshot carries every piece of execution state.  ``num_steps`` counts loop iterations — single
         block dispatches for ``earliest``/``popular``, whole sweeps for
         ``sweep`` — matching the ``steps`` counter.
         """

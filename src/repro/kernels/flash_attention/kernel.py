@@ -25,11 +25,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernels import (and run in interpret mode) across the supported range.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                   bq: int, bk: int, nk: int, scale: float, causal: bool):
@@ -86,7 +81,7 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, h, s, dh = q.shape
     hk, t = k.shape[1], k.shape[2]
@@ -121,7 +116,7 @@ def flash_attention(
             pltpu.VMEM((bq, 128), jnp.float32),  # running sum
             pltpu.VMEM((bq, dh), jnp.float32),  # accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -1,14 +1,11 @@
-"""Jitted wrapper: [B, S, H, Dh] layout in/out, CPU interpret fallback."""
+"""Jitted wrapper: [B, S, H, Dh] layout in/out (interpreted on CPU)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from . import kernel, ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -20,6 +17,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vt = jnp.swapaxes(v, 1, 2)
     out = kernel.flash_attention(
         qt, kt, vt, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=not _is_tpu(),
+        interpret=interpret_mode(),
     )
     return jnp.swapaxes(out, 1, 2)

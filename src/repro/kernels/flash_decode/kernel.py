@@ -24,11 +24,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax renamed TPUCompilerParams -> CompilerParams; accept either so the
-# kernels import (and run in interpret mode) across the supported range.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _decode_kernel(count_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, bk: int, nk: int, scale: float):
@@ -81,7 +76,7 @@ def decode_attention(
     count: jax.Array,  # [B] int32
     *,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, hk, g, dh = q.shape
     w = k.shape[2]
@@ -111,7 +106,7 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,14 +1,12 @@
-"""Jitted wrapper for decode attention ([B, H, Dh] query layout)."""
+"""Jitted wrapper for decode attention ([B, H, Dh] query layout;
+interpreted on CPU)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from . import kernel, ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def decode_attention(
@@ -26,6 +24,6 @@ def decode_attention(
     kt = jnp.swapaxes(k, 1, 2)  # [B, Hkv, W, Dh]
     vt = jnp.swapaxes(v, 1, 2)
     out = kernel.decode_attention(
-        qg, kt, vt, count, block_k=block_k, interpret=not _is_tpu()
+        qg, kt, vt, count, block_k=block_k, interpret=interpret_mode()
     )
     return out.reshape(b, h, dh)

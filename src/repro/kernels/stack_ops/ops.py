@@ -1,10 +1,12 @@
-"""Jitted wrappers for the stack kernels, handling arbitrary feature
-shapes (the VM pushes values of any rank) and the CPU/interpret fallback.
+"""Wrappers for the stack kernels in the VM's stack layout.
 
-On CPU we *validate* the Pallas kernels in interpret mode; the VM's
-default (`use_kernel=False`) uses the jnp reference, which XLA compiles
-to the same scatter/gather it would on TPU.  `use_kernel=True` routes
-through `pallas_call` (interpret on CPU, compiled on TPU).
+The VM keeps a stack as ``[D, Z, ...]`` for values of any rank; these
+wrappers flatten the value's features to ``F`` and add the unit axis the
+kernels' ``[D, Z, 1, F]`` blocks need.  The kernels run interpreted on
+CPU (where the suite validates them) and compiled on TPU
+(:func:`repro.kernels.platform.interpret_mode`).  The VM's default
+(`use_kernel=False`) uses the jnp reference, which XLA compiles to
+scatter/gather.
 
 Under lane sharding (`VMConfig.mesh`) stack traffic stays strictly
 per-lane, so :func:`shard_local` wraps the same wrappers in `shard_map`:
@@ -15,42 +17,35 @@ XLA scatter/gather path.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import kernel, ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _flatten_features(x: jax.Array, lead: int):
-    feat = x.shape[lead:]
-    f = 1
-    for s in feat:
-        f *= s
-    return x.reshape(x.shape[:lead] + (max(f, 1),)), feat
+from ..platform import interpret_mode
+from . import kernel
 
 
 def masked_push(stack: jax.Array, ptr: jax.Array, val: jax.Array,
                 mask: jax.Array) -> jax.Array:
     """stack: [D, Z, ...]; ptr/mask: [Z]; val: [Z, ...]."""
     d, z = stack.shape[:2]
-    s2, feat = _flatten_features(stack, 2)
-    v2, _ = _flatten_features(val, 1)
-    out = kernel.masked_push(s2, ptr, v2, mask, interpret=not _is_tpu())
+    f = math.prod(stack.shape[2:])
+    out = kernel.masked_push(
+        stack.reshape(d, z, 1, f), ptr, val.reshape(z, 1, f), mask,
+        interpret=interpret_mode(),
+    )
     return out.reshape(stack.shape)
 
 
 def masked_peek(stack: jax.Array, ptr: jax.Array) -> jax.Array:
     """stack: [D, Z, ...]; ptr: [Z] -> [Z, ...]."""
     d, z = stack.shape[:2]
-    s2, feat = _flatten_features(stack, 2)
-    out = kernel.masked_peek(s2, ptr, interpret=not _is_tpu())
-    return out.reshape((z,) + stack.shape[2:])
+    f = math.prod(stack.shape[2:])
+    out = kernel.masked_peek(
+        stack.reshape(d, z, 1, f), ptr, interpret=interpret_mode()
+    )
+    return out.reshape(stack.shape[1:])
 
 
 @functools.lru_cache(maxsize=None)
@@ -61,26 +56,24 @@ def shard_local(mesh):
     module-level wrapper it wraps, but runs one kernel per device over
     that device's lane slice: stacks are ``[depth, lanes, ...]`` with the
     lane axis sharded, pointers/masks/values shard their leading lane
-    axis, and feature dims stay unpartitioned.  ``check_rep=False``
+    axis, and feature dims stay unpartitioned.  ``check_vma=False``
     because Pallas calls don't participate in shard_map's replication
     inference.  Cached per mesh so VM instances and tests share the
     wrapped callables (and their jit caches).
     """
-    from jax.experimental.shard_map import shard_map
-
     axis = mesh.axis_names[0]
-    push = shard_map(
+    push = jax.shard_map(
         masked_push,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis), P(axis), P(axis)),
         out_specs=P(None, axis),
-        check_rep=False,
+        check_vma=False,
     )
-    peek = shard_map(
+    peek = jax.shard_map(
         masked_peek,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     return push, peek

@@ -1,6 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "")
+    + " --xla_force_host_platform_device_count=512"
+).strip()
+# The lines above MUST run before any jax import: jax locks the device
 # count at first init, and the dry-run needs 512 placeholder host devices
 # to build the production meshes.  (Only this entry point does this —
 # tests and benches see the single real CPU device.)
@@ -323,7 +326,10 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         # the canonical 16x16 dry-run proof is separate.
         axes = (("pod", "data", "model") if len(mesh_shape) == 3
                 else ("data", "model"))
-        mesh = jax.make_mesh(tuple(mesh_shape), axes)
+        mesh = jax.make_mesh(
+            tuple(mesh_shape), axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(mesh_shape),
+        )
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     chips = num_chips(mesh)

@@ -346,7 +346,10 @@ def make_nuts_kernel(
     device count, or a 1-D ``jax.sharding.Mesh``) shards the chain axis
     across devices — chains are embarrassingly parallel, so the only
     cross-device traffic is the VM's scalar dispatch reductions, and the
-    sampled chains are bit-identical to the unsharded run.
+    sampled chains are bit-identical to the unsharded run wherever XLA
+    compiles the target's gradient the same at the per-device batch size
+    (on TPU, not for a contraction it tiles by batch size, such as
+    logistic regression over many data points).
     ``compact_every=k`` turns on occupancy-aware lane compaction every
     ``k`` VM dispatches — tree-depth divergence between chains is exactly
     the fragmentation compaction recovers; chains stay bit-identical.

@@ -75,7 +75,10 @@ class TestShardingRules:
     def mesh(self):
         # a tiny abstract mesh over the single CPU device set is enough to
         # exercise the rule logic (device count 1, axis sizes 1x1)
-        return jax.make_mesh((1, 1), ("data", "model"))
+        return jax.make_mesh(
+            (1, 1), ("data", "model"),
+            axis_types=(jax.sharding.AxisType.Auto,) * 2,
+        )
 
     def test_indivisible_axis_dropped(self, mesh):
         from repro.launch import sharding as sh
