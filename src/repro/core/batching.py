@@ -231,27 +231,29 @@ class _PcExecutor:
     def _qualify(self, inputs: dict[str, Any]) -> dict[str, Any]:
         return {ir.qualify(self.main, k): v for k, v in inputs.items()}
 
-    def run(self, inputs: dict[str, Any]) -> dict[str, Any]:
-        res = self.vm.run(self._qualify(inputs))
+    def run(self, inputs: dict[str, Any],
+            clock: pc_vm.RunClock) -> dict[str, Any]:
+        res = self.vm.run(self._qualify(inputs), clock)
         self.last_result = res
         if self.vm.config.on_fault == "raise":
             # Batch-fatal policy (the historical default): a deliberate
             # device sync before results escape the pytree API.  Under
             # "quarantine" nothing raises — faulted lanes are flagged in
             # last_result.fault_code and healthy lanes stay exact.
-            if res.depth_exceeded is not None:
-                _raise_if_overflowed(
-                    jax.device_get(res.depth_exceeded),
-                    self.batch_size, self.vm.config.max_depth,
-                    self.overflow_hint,
-                )
-            cfg = self.vm.config
-            if res.fault_code is not None and (
-                cfg.detect_nonfinite or cfg.lane_step_budget is not None
-            ):
-                _raise_if_faulted(
-                    jax.device_get(res.fault_code), self.batch_size
-                )
+            with clock.phase("autobatch.check"):
+                if res.depth_exceeded is not None:
+                    _raise_if_overflowed(
+                        clock.read(res.depth_exceeded),
+                        self.batch_size, self.vm.config.max_depth,
+                        self.overflow_hint,
+                    )
+                cfg = self.vm.config
+                if res.fault_code is not None and (
+                    cfg.detect_nonfinite or cfg.lane_step_budget is not None
+                ):
+                    _raise_if_faulted(
+                        clock.read(res.fault_code), self.batch_size
+                    )
         return {k.split("/", 1)[1]: v for k, v in res.outputs.items()}
 
     def lower(self, inputs: dict[str, Any]):
@@ -975,21 +977,32 @@ class AutobatchedFunction:
     # ------------------------------------------------------------------
 
     def __call__(self, *args):
-        inputs, z = self._bind(args)
-        key = self._aval_key(inputs, z)
-        ex = self._aval_cache.get(key)
-        if ex is None:
-            self._misses += 1
-            ex = self._executor(z)
-            self._aval_cache[key] = ex
-        else:
-            self._hits += 1
-        self._last_executor = ex
-        out = ex.run(inputs)
-        return jax.tree_util.tree_unflatten(
-            self._iface.out_treedef,
-            [out[name] for name in self._iface.out_leaves],
-        )
+        # Host phases of the call, as profiler spans and as seconds on the
+        # pc backend's last_result.sched.host_phases (see pc_vm.RunClock).
+        clock = pc_vm.RunClock()
+        with clock.phase("autobatch.call"):
+            with clock.phase("autobatch.bind"):
+                inputs, z = self._bind(args)
+                key = self._aval_key(inputs, z)
+                ex = self._aval_cache.get(key)
+                if ex is None:
+                    self._misses += 1
+                    ex = self._executor(z)
+                    self._aval_cache[key] = ex
+                else:
+                    self._hits += 1
+            self._last_executor = ex
+            if self.backend == "pc":
+                out = ex.run(inputs, clock)
+            else:
+                out = ex.run(inputs)
+            result = jax.tree_util.tree_unflatten(
+                self._iface.out_treedef,
+                [out[name] for name in self._iface.out_leaves],
+            )
+        if self.backend == "pc":
+            clock.stamp(ex.last_result)
+        return result
 
     def lower(self, *args) -> AotLowered:
         """AOT-lower the full batched computation for these avals (pc only)."""

@@ -142,11 +142,27 @@ newest events win and the drain reports how many oldest were dropped.
 Under a mesh the buffers are replicated; the per-event counts are the
 same integer all-reduces the stats path uses, so tracing composes with
 sharding, segments, compaction and quarantine.
+
+Profiler names (metadata only; the compiled program is the same):
+
+On the host, ``run()`` opens ``jax.profiler.TraceAnnotation`` spans
+``pcvm.run`` > ``pcvm.start`` / ``pcvm.launch`` / ``pcvm.result`` >
+``pcvm.wait`` (the call's first blocking read, where the host waits for
+the loop), timed by a :class:`RunClock` whose seconds per phase and count
+of blocking reads land in ``SchedulerStats.host_phases`` /
+``host_syncs`` in every call, traced or not.  On the device, the loop
+body's operations sit under ``jax.named_scope`` names: ``pcvm.pick``,
+``pcvm.stats``, ``pcvm.cond``, ``pcvm.compact`` and ``pcvm.switch``
+around the dispatch machinery, ``pcvm.block<i>`` around each block body,
+and inside a block ``pcvm.write`` (masked top writes), ``pcvm.push`` /
+``pcvm.pop`` (stack traffic) and ``pcvm.prim.<tag>`` (tagged primitives).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field, replace
 from typing import Any, Callable, Optional
 
 import jax
@@ -414,6 +430,13 @@ class SchedulerStats:
     # the quantity StateLayoutPacking shrinks — packed members write one
     # grouped array instead of one `_masked` update per member.
     masked_updates: Optional[int] = None
+    # Host seconds per phase of the call that produced this result, keyed
+    # by the phase's profiler span (``autobatch.*`` / ``pcvm.*``), each
+    # less the phases nested in it (see RunClock), and the blocking device
+    # reads the call made.  Recorded in every call, traced or not.
+    host_phases: dict[str, float] = field(default_factory=dict,
+                                          compare=False)
+    host_syncs: int = field(default=0, compare=False)
 
 
 @dataclass
@@ -438,6 +461,51 @@ class VMResult:
         if self.fault_code is None:
             return None
         return self.fault_code != FAULT_OK
+
+
+class RunClock:
+    """Host time per named phase of one call, and its blocking reads.
+
+    ``phase(name)`` is a ``jax.profiler.TraceAnnotation`` of that name
+    that also adds its seconds (``time.perf_counter``), less those of the
+    phases nested in it, to ``phases[name]``; so a call's phases add up to
+    its outermost one, and in a profiler trace every device-idle gap has
+    one innermost phase.  ``read(x)`` is a counted blocking
+    ``jax.device_get``; the call's first read, where the host waits for
+    the device to finish the loop, runs under ``pcvm.wait``.
+    ``stamp(res)`` records both on ``res.sched``.
+    """
+
+    def __init__(self):
+        self.phases: dict[str, float] = {}
+        self.syncs = 0
+        self._nested = [0.0]  # seconds of nested phases, per open phase
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        self._nested.append(0.0)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            took = time.perf_counter() - t0
+            inner = self._nested.pop()
+            self._nested[-1] += took
+            self.phases[name] = self.phases.get(name, 0.0) + took - inner
+
+    def read(self, x):
+        self.syncs += 1
+        if self.syncs > 1:
+            return jax.device_get(x)
+        with self.phase("pcvm.wait"):
+            return jax.device_get(x)
+
+    def stamp(self, res: "VMResult") -> "VMResult":
+        if res.sched is not None:
+            res.sched = replace(res.sched, host_phases=dict(self.phases),
+                                host_syncs=self.syncs)
+        return res
 
 
 class ProgramCounterVM:
@@ -760,7 +828,10 @@ class ProgramCounterVM:
                 else:
                     if detect_nonfinite:
                         check_finite(val)
-                    tops[v] = _masked(mask, val.astype(tops[v].dtype), tops[v])
+                    with jax.named_scope("pcvm.write"):
+                        tops[v] = _masked(
+                            mask, val.astype(tops[v].dtype), tops[v]
+                        )
 
             for op in blk.ops:
                 if isinstance(op, ir.LPrim):
@@ -777,7 +848,9 @@ class ProgramCounterVM:
                         )
                     else:
                         fn = op.fn if op.batched else jax.vmap(op.fn)
-                        outs = fn(*[read(i) for i in op.ins])
+                        with (jax.named_scope(f"pcvm.prim.{op.tag}")
+                              if op.tag else contextlib.nullcontext()):
+                            outs = fn(*[read(i) for i in op.ins])
                         if len(op.outs) == 1:
                             outs = (outs,)
                     for name, val in zip(op.outs, outs):
@@ -789,27 +862,30 @@ class ProgramCounterVM:
                     )
                     depth_exceeded = jnp.logical_or(depth_exceeded, overflow)
                     set_fault(overflow, FAULT_STACK_OVERFLOW)
-                    if use_kernel:
-                        stacks[op.var] = kernel_push(
-                            stacks[op.var], ptrs[op.var], old_top, mask
-                        )
-                    else:
-                        stacks[op.var] = _scatter_push(
-                            stacks[op.var], ptrs[op.var], old_top, mask
-                        )
-                    ptrs[op.var] = ptrs[op.var] + imask
+                    with jax.named_scope("pcvm.push"):
+                        if use_kernel:
+                            stacks[op.var] = kernel_push(
+                                stacks[op.var], ptrs[op.var], old_top, mask
+                            )
+                        else:
+                            stacks[op.var] = _scatter_push(
+                                stacks[op.var], ptrs[op.var], old_top, mask
+                            )
+                        ptrs[op.var] = ptrs[op.var] + imask
                     new_top = read(op.src)
                     if detect_nonfinite:
                         check_finite(new_top)
-                    tops[op.var] = _masked(mask, new_top, old_top)
+                    with jax.named_scope("pcvm.push"):
+                        tops[op.var] = _masked(mask, new_top, old_top)
                 elif isinstance(op, ir.LPop):
-                    new_ptr = ptrs[op.var] - imask
-                    if use_kernel:
-                        restored = kernel_peek(stacks[op.var], new_ptr)
-                    else:
-                        restored = _gather_top(stacks[op.var], new_ptr)
-                    tops[op.var] = _masked(mask, restored, tops[op.var])
-                    ptrs[op.var] = new_ptr
+                    with jax.named_scope("pcvm.pop"):
+                        new_ptr = ptrs[op.var] - imask
+                        if use_kernel:
+                            restored = kernel_peek(stacks[op.var], new_ptr)
+                        else:
+                            restored = _gather_top(stacks[op.var], new_ptr)
+                        tops[op.var] = _masked(mask, restored, tops[op.var])
+                        ptrs[op.var] = new_ptr
                 else:  # pragma: no cover
                     raise AssertionError(op)
 
@@ -830,12 +906,14 @@ class ProgramCounterVM:
                 pc_overflow = jnp.logical_and(mask, pc_ptr >= max_depth)
                 depth_exceeded = jnp.logical_or(depth_exceeded, pc_overflow)
                 set_fault(pc_overflow, FAULT_STACK_OVERFLOW)
-                pc_stack = _scatter_push(pc_stack, pc_ptr, ret, mask)
-                pc_ptr = pc_ptr + imask
+                with jax.named_scope("pcvm.push"):
+                    pc_stack = _scatter_push(pc_stack, pc_ptr, ret, mask)
+                    pc_ptr = pc_ptr + imask
                 pc_top = jnp.where(mask, t.target, pc_top)
             elif isinstance(t, ir.LReturn):
-                new_ptr = pc_ptr - imask
-                restored = _gather_top(pc_stack, new_ptr)
+                with jax.named_scope("pcvm.pop"):
+                    new_ptr = pc_ptr - imask
+                    restored = _gather_top(pc_stack, new_ptr)
                 pc_top = jnp.where(mask, restored, pc_top)
                 pc_ptr = new_ptr
             else:  # pragma: no cover
@@ -868,10 +946,11 @@ class ProgramCounterVM:
             return out
 
         def scoped_run(state: dict[str, Any]) -> dict[str, Any]:
-            # Label the block body in the HLO metadata so device profiles
-            # (jax.profiler / XProf) line up with DispatchTrace events by
-            # block id.  Pure metadata — numerics and scheduling are
-            # untouched.
+            # Label the block body in the HLO metadata, so a device
+            # profile (jax.profiler / XProf) reads time per block, lines up
+            # with DispatchTrace events by block id, and splits by the
+            # scopes inside (pcvm.write/push/pop/prim.<tag>).  Pure
+            # metadata: numerics and scheduling are untouched.
             with jax.named_scope(f"pcvm.block{bidx}"):
                 return run(state)
 
@@ -935,24 +1014,26 @@ class ProgramCounterVM:
     def _liveness_cond(self, state: dict[str, Any]) -> Array:
         # Global liveness: ``any`` over the lane axis — a single bool
         # all-reduce per iteration under a mesh.
-        cond = jnp.logical_and(
-            state["steps"] < self.config.max_steps,
-            jnp.any(self._live_mask(state)),
-        )
-        if self.config.on_fault == "raise" and (
-            self.config.detect_nonfinite
-            or self.config.lane_step_budget is not None
-        ):
-            # Fail fast: a NONFINITE/WATCHDOG fault is batch-fatal under
-            # "raise", so stop the loop instead of spinning to max_steps
-            # (a livelocked lane would otherwise never let cond go false).
+        with jax.named_scope("pcvm.cond"):
             cond = jnp.logical_and(
-                cond,
-                jnp.logical_not(
-                    jnp.any(state["fault_code"] >= FAULT_NONFINITE)
-                ),
+                state["steps"] < self.config.max_steps,
+                jnp.any(self._live_mask(state)),
             )
-        return cond
+            if self.config.on_fault == "raise" and (
+                self.config.detect_nonfinite
+                or self.config.lane_step_budget is not None
+            ):
+                # Fail fast: a NONFINITE/WATCHDOG fault is batch-fatal
+                # under "raise", so stop the loop instead of spinning to
+                # max_steps (a livelocked lane would otherwise never let
+                # cond go false).
+                cond = jnp.logical_and(
+                    cond,
+                    jnp.logical_not(
+                        jnp.any(state["fault_code"] >= FAULT_NONFINITE)
+                    ),
+                )
+            return cond
 
     def _trace_event(
         self, state: dict[str, Any], block: Any, dispatch_mask: Array
@@ -1030,17 +1111,22 @@ class ProgramCounterVM:
             return m
 
         def body_switch(state):
-            i = self._pick_block(state)
+            with jax.named_scope("pcvm.pick"):
+                i = self._pick_block(state)
             if collect:
-                m = resident(state, i)
-                active = jnp.sum(m.astype(_I32))
-                state = dict(state)
-                state["block_exec"] = state["block_exec"].at[i].add(1)
-                state["block_active"] = state["block_active"].at[i].add(active)
-                state["tile_acc"] = state["tile_acc"] + _tile_capacity(m)
+                with jax.named_scope("pcvm.stats"):
+                    m = resident(state, i)
+                    active = jnp.sum(m.astype(_I32))
+                    state = dict(state)
+                    state["block_exec"] = state["block_exec"].at[i].add(1)
+                    state["block_active"] = (
+                        state["block_active"].at[i].add(active)
+                    )
+                    state["tile_acc"] = state["tile_acc"] + _tile_capacity(m)
             ev = self._trace_event(state, i, resident(state, i)) if tracing \
                 else None
-            state = lax.switch(i, self._block_fns, state)
+            with jax.named_scope("pcvm.switch"):
+                state = lax.switch(i, self._block_fns, state)
             state = dict(state)
             state["steps"] = state["steps"] + 1
             if tracing:
@@ -1060,20 +1146,22 @@ class ProgramCounterVM:
             # several (forward) blocks within one sweep.
             for b, fn in enumerate(self._block_fns):
                 if collect:
-                    m = resident(state, b)
-                    active = jnp.sum(m.astype(_I32))
-                    state = dict(state)
-                    # Count a dispatch only when it had resident members,
-                    # so utilization stays comparable across schedules.
-                    state["block_exec"] = (
-                        state["block_exec"].at[b].add((active > 0).astype(_I32))
-                    )
-                    state["block_active"] = (
-                        state["block_active"].at[b].add(active)
-                    )
-                    state["tile_acc"] = state["tile_acc"] + jnp.where(
-                        active > 0, _tile_capacity(m), 0
-                    )
+                    with jax.named_scope("pcvm.stats"):
+                        m = resident(state, b)
+                        active = jnp.sum(m.astype(_I32))
+                        state = dict(state)
+                        # Count a dispatch only when it had resident
+                        # members, so utilization stays comparable across
+                        # schedules.
+                        state["block_exec"] = state["block_exec"].at[b].add(
+                            (active > 0).astype(_I32)
+                        )
+                        state["block_active"] = (
+                            state["block_active"].at[b].add(active)
+                        )
+                        state["tile_acc"] = state["tile_acc"] + jnp.where(
+                            active > 0, _tile_capacity(m), 0
+                        )
                 state = fn(state)
             state = dict(state)
             state["steps"] = state["steps"] + 1
@@ -1127,18 +1215,19 @@ class ProgramCounterVM:
         k = self.config.compact_every
         if k is None:
             return state
-        if k == 1:
-            return self._compact(state)
-        # ``steps`` was just incremented, so the first compaction lands
-        # after dispatch k — a traced-counter condition, shared by the
-        # single-shot and segmented loops (steps is global), so segment
-        # boundaries never change where compaction happens.
-        return lax.cond(
-            state["steps"] % k == 0,
-            self._compact,
-            lambda s: self._shard_state(dict(s)),
-            state,
-        )
+        with jax.named_scope("pcvm.compact"):
+            if k == 1:
+                return self._compact(state)
+            # ``steps`` was just incremented, so the first compaction lands
+            # after dispatch k — a traced-counter condition, shared by the
+            # single-shot and segmented loops (steps is global), so segment
+            # boundaries never change where compaction happens.
+            return lax.cond(
+                state["steps"] % k == 0,
+                self._compact,
+                lambda s: self._shard_state(dict(s)),
+                state,
+            )
 
     def _lane_restore(self, state: dict[str, Any]) -> Optional[Array]:
         """Inverse lane permutation (row -> original order), or None when
@@ -1188,20 +1277,29 @@ class ProgramCounterVM:
 
         return lax.while_loop(cond, self._make_body(), state)
 
-    def run(self, inputs: dict[str, Array]) -> VMResult:
+    def run(
+        self, inputs: dict[str, Array], clock: Optional[RunClock] = None
+    ) -> VMResult:
         """Execute the batched program to completion.
 
         Runs two jitted stages — state construction, then the while loop
         with the state pytree donated into it — so a run never holds more
-        than one copy of the VM state.
+        than one copy of the VM state.  A caller that passes its own
+        ``clock`` adds phases to it and stamps the result itself.
         """
-        # Host-side profiler annotation: a jax.profiler trace of the
-        # caller shows VM runs as named spans that device profiles (and
-        # DispatchTrace timelines) can be lined up against.
-        with jax.profiler.TraceAnnotation("pcvm.run"):
-            state = self._jitted_start(inputs)
-            state = self._jitted_loop(state)
-            return self._result(state)
+        # Host-side profiler spans: a jax.profiler trace of the caller
+        # shows VM runs, and each host phase of one, as named spans that
+        # device profiles (and DispatchTrace timelines) line up against.
+        own = clock is None
+        clock = RunClock() if own else clock
+        with clock.phase("pcvm.run"):
+            with clock.phase("pcvm.start"):
+                state = self._jitted_start(inputs)
+            with clock.phase("pcvm.launch"):
+                state = self._jitted_loop(state)
+            with clock.phase("pcvm.result"):
+                res = self._result(state, clock.read)
+        return clock.stamp(res) if own else res
 
     # ------------------------------------------------------------------
     # Segmented (resumable) execution
@@ -1367,12 +1465,15 @@ class ProgramCounterVM:
         the device (it reads the buffers) but does not consume them, so
         a later drain sees the same events plus any new ones.
         """
+        return self._drain_trace(state, jax.device_get)
+
+    def _drain_trace(self, state: dict[str, Any], read: Callable):
         if self.trace_capacity is None:
             return None
         from repro.obs.trace import drain
 
-        buffers = jax.device_get(state["trace"])
-        total = int(jax.device_get(state["steps"]))
+        buffers = read(state["trace"])
+        total = int(read(state["steps"]))
         return drain(
             buffers,
             total=total,
@@ -1381,7 +1482,10 @@ class ProgramCounterVM:
             batch_size=self.config.batch_size,
         )
 
-    def _result(self, state) -> VMResult:
+    def _result(self, state, read: Optional[Callable] = None) -> VMResult:
+        """``read`` makes every blocking device read (``jax.device_get``
+        by default; ``RunClock.read`` counts them)."""
+        read = jax.device_get if read is None else read
         lp = self.lowered
         # Restore caller lane order on every per-lane array (identity when
         # compaction is off) — compaction must be invisible in results.
@@ -1405,14 +1509,14 @@ class ProgramCounterVM:
         steps = None
         masked_updates = None
         if block_exec is not None:
-            be = jax.device_get(block_exec)
-            ba = jax.device_get(block_active)
+            be = read(block_exec)
+            ba = read(block_active)
             for tag, entries in self._tag_blocks.items():
                 execs = sum(int(be[b]) * m for b, m in entries)
                 active = sum(int(ba[b]) * m for b, m in entries)
                 tag_stats[tag] = (execs, active)
             dispatches = int(be.sum())
-            tile_cap = int(jax.device_get(state["tile_acc"]))
+            tile_cap = int(read(state["tile_acc"]))
             if dispatches:
                 # Tile-based SIMD occupancy: actives / occupied-tile
                 # capacity (see OCCUPANCY_TILE).  The legacy whole-batch
@@ -1422,7 +1526,7 @@ class ProgramCounterVM:
                 )
             if tile_cap:
                 mean_occ = float(ba.sum()) / tile_cap
-            steps = int(jax.device_get(state["steps"]))
+            steps = int(read(state["steps"]))
             masked_updates = sum(
                 int(be[b]) * w for b, w in enumerate(self._masked_writes)
             )
@@ -1452,7 +1556,7 @@ class ProgramCounterVM:
             # Tracing syncs here (the drain reads the device buffers) —
             # like collect_block_stats, enabling it trades result-time
             # asynchrony for observability.
-            trace=self.get_trace(state),
+            trace=self._drain_trace(state, read),
         )
 
     # ------------------------------------------------------------------
@@ -1475,7 +1579,9 @@ class ProgramCounterVM:
                 for fn in self._block_fns:
                     state = fn(state)
                 return state
-            i = self._pick_block(state)
-            return lax.switch(i, self._block_fns, state)
+            with jax.named_scope("pcvm.pick"):
+                i = self._pick_block(state)
+            with jax.named_scope("pcvm.switch"):
+                return lax.switch(i, self._block_fns, state)
 
         return step
