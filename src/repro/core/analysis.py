@@ -458,8 +458,10 @@ class CallGraph:
     def is_recursive(self, callee: str) -> bool:
         """Can ``callee`` transitively have two live frames at once?
 
-        If so, arguments must be pushed onto the parameter stacks (burying the
-        outer frame's values) rather than overwriting the tops.
+        If so, an argument may have to be pushed onto the parameter's stack
+        (burying an outer frame's value) rather than overwrite the top; the
+        call lowering pushes it only where a frame reads the buried value
+        (``lowering.lower``, the ``Call`` branch).
         """
         return callee in self._reach[callee]
 

@@ -172,4 +172,5 @@ def fuse_chains(low: ir.LoweredProgram) -> ir.LoweredProgram:
         fused_from=fused_from,
         block_weights=block_weights,
         state_layout=low.state_layout,
+        param_pushes_elided=low.param_pushes_elided,
     )

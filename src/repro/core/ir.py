@@ -311,6 +311,9 @@ class LoweredProgram:
     # Packed-state layout recorded by ``StateLayoutPacking`` (see
     # :class:`StateLayout`); ``None`` when state is unpacked.
     state_layout: Optional[StateLayout] = None
+    # Argument passings into a recursive callee that the lowering made plain
+    # writes of the parameter's top instead of pushes (opt. i's save rule).
+    param_pushes_elided: int = 0
 
     @property
     def exit_index(self) -> int:

@@ -7,9 +7,12 @@ optimizations:
 * **Caller-saves, per-variable stacks** (opt. i): at each call site that can
   re-enter the caller's frame, the caller pushes every variable that is live
   after the call (minus the call's outputs).  Argument passing into a
-  recursive callee is itself a push onto the parameter's stack (burying the
-  outer frame's value); the caller pops everything it pushed after the call
-  returns.
+  recursive callee is a push onto the parameter's stack (burying the outer
+  frame's value) only where an outer frame still reads that value: at a
+  self-call, the parameters live after the call; from a function the callee
+  cannot re-enter, none (no callee frame lies below); across mutual
+  recursion, all of them.  Every other argument overwrites the parameter's
+  top.  The caller pops everything it pushed after the call returns.
 * **Temporaries** (opt. ii): variables whose every read is preceded by a
   write within the same lowered block never enter VM state at all — they are
   ordinary intermediate values inside the fused block body.
@@ -56,6 +59,7 @@ def lower(
     blockmap: dict[tuple[str, int], int] = {}
     func_entries: dict[str, int] = {}
     tmp_counter = itertools.count()
+    param_pushes_elided = 0
 
     def fresh(fname: str) -> str:
         return ir.qualify(fname, f"%arg{next(tmp_counter)}")
@@ -91,15 +95,28 @@ def lower(
                 # ---- Call lowering ----
                 callee = program.functions[op.callee]
                 reenters = cg.can_reenter(fname, op.callee)
-                recursive = cg.is_recursive(op.callee)
+                live = lv.live_after(bi, oi) - set(op.outs)
+                # Which of the callee's params take their argument by a push,
+                # burying the value below for an outer frame that reads it:
+                # * self-call: the caller's own params it still reads after
+                #   the call (the push is that param's save);
+                # * mutual recursion: every param.  The outer frame of the
+                #   callee may belong to a call site in another function,
+                #   whose liveness is not known here;
+                # * a callee that cannot re-enter the caller (it may recurse
+                #   itself): none, as no frame of the callee lies below.
+                if op.callee == fname:
+                    push_params = live & set(callee.params)
+                elif reenters:
+                    push_params = set(callee.params)
+                else:
+                    push_params = set()
                 # Save set: caller vars live after the call, minus the call's
-                # own outputs, minus callee params (recursive self-calls pass
-                # args by pushing the param itself, which is the save).
+                # own outputs, minus the params a self-call pushes itself.
                 saves: list[str] = []
                 if reenters:
-                    live = lv.live_after(bi, oi) - set(op.outs)
                     if op.callee == fname:
-                        live -= set(callee.params)
+                        live -= push_params
                     saves = sorted(q(v) for v in live)
                 # Argument values: route through fresh temps when the callee
                 # is the caller (param writes could clobber arg reads).
@@ -117,11 +134,13 @@ def lower(
                 pushed_params: list[str] = []
                 for p, src in zip(callee.params, arg_srcs):
                     pq = ir.qualify(op.callee, p)
-                    if recursive:
+                    if p in push_params:
                         cur.ops.append(ir.LPush(var=pq, src=src))
                         pushed_params.append(pq)
                     else:
                         cur.ops.append(ir.identity_prim(pq, src, name="argset"))
+                        if cg.is_recursive(op.callee):
+                            param_pushes_elided += 1
                 ret_idx = len(lowered)
                 cur.term = ir.LPushJump(target=("entry", op.callee), ret=ret_idx)
                 # ---- Return-site block ----
@@ -176,6 +195,7 @@ def lower(
         stack_vars=stack_vars,
         temp_vars=temp_vars,
         func_entries=func_entries,
+        param_pushes_elided=param_pushes_elided,
     )
     # The block-local optimizations ((v) pop-push elimination, (ii) temp
     # detection) run as pipeline passes over the raw emission.

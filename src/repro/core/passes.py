@@ -31,6 +31,7 @@ Passes:
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Protocol, Sequence, runtime_checkable
 
@@ -642,6 +643,11 @@ class Diagnostics:
     num_state_vars: int  # masked top buffers the VM updates per dispatch
     num_stack_vars: int
     num_temp_vars: int
+    # Argument passings into a recursive callee lowered as plain writes
+    # instead of pushes, and the bytes one lane's variable stacks hold per
+    # depth row (times max_depth and the batch: the stacks on the device).
+    param_pushes_elided: int
+    stack_bytes_per_row: int
     dead_state_vars: tuple[str, ...]  # state DCE would remove
     dead_ops: int  # ops DCE would remove
     pc_depth: Optional[int]
@@ -663,6 +669,8 @@ class Diagnostics:
             f"state vars:    {self.num_state_vars} "
             f"(stack: {self.num_stack_vars}, temps excluded: "
             f"{self.num_temp_vars})",
+            f"stacks:        {self.stack_bytes_per_row} B per lane per depth "
+            f"row ({self.param_pushes_elided} param pushes elided)",
         ]
         if self.dead_ops or self.dead_state_vars:
             lines.append(
@@ -730,6 +738,12 @@ def diagnose(lowered: ir.LoweredProgram) -> Diagnostics:
         num_state_vars=len(state_vars),
         num_stack_vars=len(lowered.stack_vars),
         num_temp_vars=len(lowered.temp_vars),
+        param_pushes_elided=lowered.param_pushes_elided,
+        stack_bytes_per_row=sum(
+            lowered.var_specs[v].dtype.itemsize
+            * math.prod(lowered.var_specs[v].shape)
+            for v in lowered.stack_vars
+        ),
         dead_state_vars=dead_state,
         dead_ops=dead_ops,
         pc_depth=depth.pc_depth,

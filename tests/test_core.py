@@ -101,7 +101,9 @@ class TestLowering:
         fb.call("f", [t], out="a")
         # Second sibling call with an argument that does NOT read n:
         fb.call("f", ["a"], out="b")
-        fb.assign("out", lambda a, b: a + b, ["a", "b"])
+        # n stays live across both calls, so both push it (a param dead
+        # after a self-call is written, not pushed).
+        fb.assign("out", lambda a, b, n: a + b + n, ["a", "b", "n"])
         fb.return_()
         pb.add(fb)
         low = lowering.lower(pb.build())

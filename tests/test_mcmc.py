@@ -24,12 +24,19 @@ STATE_KEYS = ("theta", "sum_theta", "sum_sq")
 
 class TestNutsProgram:
     def test_lowering_structure(self, small_nuts):
-        """The recursion forces stacks exactly on build_tree's frame state."""
+        """The recursion forces stacks exactly on the build_tree frame state
+        that a frame still reads after a self-call returns."""
         t, s, _ = small_nuts
         low = lowering.lower(nuts.build_nuts_program(t, s))
-        # The recursive frame's parameters must be stacked.
-        for v in ["build_tree/theta", "build_tree/r", "build_tree/j"]:
-            assert v in low.stack_vars
+        assert low.stack_vars == {
+            "build_tree/" + v
+            for v in ("tm", "rm", "tp", "rp", "th1", "log_u", "v", "eps",
+                      "jm1", "k3", "n1", "key_out")
+        }
+        # Params no frame reads after a self-call take their argument by a
+        # plain write: the first self-call reads theta/r/j/key only before.
+        for v in ["theta", "r", "j", "key"]:
+            assert "build_tree/" + v not in low.stack_vars
         # Chain-level accumulators never cross a recursive call.
         assert "nuts_chain/sum_theta" not in low.stack_vars
         assert "nuts_chain/sum_sq" not in low.stack_vars
